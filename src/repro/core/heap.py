@@ -45,6 +45,16 @@ class SymPtr(NamedTuple):
 
 
 @dataclasses.dataclass
+class StoreTally:
+    """Whole-pool rebuilds of one heap and every functional copy made from
+    it: each store (``write``, ``write_all``, put, signal, flush) replaces a
+    whole ``(npes, words)`` pool array in :meth:`SymmetricHeap.replace_pool`.
+    Copies share the one object, so a reader takes deltas across a region."""
+    stores: int = 0
+    store_bytes: int = 0
+
+
+@dataclasses.dataclass
 class SymmetricHeap:
     """Functional symmetric heap.  Mutating ops return a new heap."""
 
@@ -53,6 +63,7 @@ class SymmetricHeap:
     _cursor: dict = dataclasses.field(default_factory=dict)
     _free: dict = dataclasses.field(default_factory=dict)
     words_per_pool: int = 1 << 20
+    tally: StoreTally = dataclasses.field(default_factory=StoreTally)
 
     # ----------------------------------------------------------- allocation
     def malloc(self, shape, dtype) -> SymPtr:
@@ -184,10 +195,11 @@ class SymmetricHeap:
     def replace_pool(self, dt, pool) -> "SymmetricHeap":
         pools = dict(self.pools)
         pools[dt] = pool
-        new = SymmetricHeap(self.npes, pools, dict(self._cursor),
-                            {k: list(v) for k, v in self._free.items()},
-                            self.words_per_pool)
-        return new
+        self.tally.stores += 1
+        self.tally.store_bytes += pool.nbytes
+        return SymmetricHeap(self.npes, pools, dict(self._cursor),
+                             {k: list(v) for k, v in self._free.items()},
+                             self.words_per_pool, self.tally)
 
 
 def create(npes: int, words_per_pool: int = 1 << 20) -> SymmetricHeap:

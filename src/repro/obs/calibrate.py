@@ -77,8 +77,10 @@ def _stats(vals: List[float]) -> dict:
 
 def report_from_samples(samples: Iterable[prof_mod.ProfSample], *,
                         worst: int = 8) -> dict:
-    """The divergence report (JSON-able, deterministic given the samples)."""
-    samples = list(samples)
+    """The divergence report (JSON-able, deterministic given the samples).
+    Host spans are left out: they enclose the scopes they would count
+    again."""
+    samples = [s for s in samples if s.path != prof_mod.HOST_PATH]
     groups: Dict[BucketKey, List[prof_mod.ProfSample]] = {}
     for s in samples:
         groups.setdefault(
@@ -169,9 +171,12 @@ def sink_join(sink: telemetry_mod.TelemetrySink, *,
 
 def measured_overlay(samples: Iterable[prof_mod.ProfSample]) -> dict:
     """Per-critical-path-segment measured wall seconds — the overlay the
-    analyzer prints next to its step-clocked segment attribution."""
+    analyzer prints next to its step-clocked segment attribution (host
+    spans left out, as in :func:`report_from_samples`)."""
     seg: Dict[str, dict] = {}
     for s in samples:
+        if s.path == prof_mod.HOST_PATH:
+            continue
         name = OP_SEGMENT.get(s.op, "other")
         row = seg.setdefault(name, {"wall_s": 0.0, "model_s": 0.0, "n": 0})
         row["wall_s"] += s.wall_s

@@ -28,7 +28,8 @@ without touching the deterministic side:
 
 Profiling off is the shared :data:`NULL_PROF` (or an unset ``ctx.prof``):
 scopes are no-ops, ``ps(x)`` is identity, nothing is recorded, and every
-deterministic output stays bitwise-identical.
+deterministic output stays bitwise-identical.  Instrumented code opens its
+scopes through :func:`scope`, which makes that check once per site.
 """
 from __future__ import annotations
 
@@ -189,7 +190,8 @@ class Profiler:
             # decimate, keep spread — same policy as StatBucket reservoirs
             self.dropped += len(self.samples) - len(self.samples[::2])
             self.samples = self.samples[::2]
-        if self.sink_records and self.ctx is not None:
+        if (self.sink_records and self.ctx is not None
+                and sc.path != HOST_PATH):
             self.ctx.telemetry.record(telemetry_mod.OpRecord(
                 sc.op, sc.nbytes, sc.path, sc.tier, wall_s,
                 sc.work_items, telemetry_mod.WALLCLOCK_SOURCE))
@@ -238,6 +240,26 @@ class _NullProf(Profiler):
 
 
 NULL_PROF = _NullProf()
+
+#: the path of a host span: a region whose host work is timed (no ``ps``
+#: sync) and which encloses other scopes.  Its samples are kept, but out of
+#: the telemetry sink and the calibration reports, which would otherwise
+#: count the enclosed scopes' seconds again.
+HOST_PATH = "host"
+#: labels of a host span
+HOST_SPAN = {"nbytes": 0, "path": HOST_PATH, "tier": "local"}
+
+
+def scope(ctx, op: str, labels=None):
+    """``ctx.prof``'s scope for ``op``, or the shared no-op scope when
+    profiling is off (one attribute check).  ``labels`` is a callable that
+    returns :meth:`Profiler.scope`'s keyword labels; it runs only when
+    profiling is on, so a label that reads the device costs nothing off.
+    Without ``labels`` the scope is a host span (:data:`HOST_SPAN`)."""
+    pf = getattr(ctx, "prof", None)
+    if pf is None or not pf.enabled:
+        return _NULL_SCOPE
+    return pf.scope(op, **(labels() if labels is not None else HOST_SPAN))
 
 
 def load_samples(path: str) -> List[ProfSample]:

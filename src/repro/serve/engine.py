@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import kvcache, model
+from repro.obs import prof
 
 
 @dataclasses.dataclass
@@ -54,10 +55,17 @@ class Engine:
         self.cfg = cfg_arch
         self.params = params
         self.max_len = max_len
-        self._prefill = jax.jit(
-            lambda p, b, c: model.prefill(p, cfg_arch, b, c))
-        self._decode = jax.jit(
-            lambda p, t, pos, c: model.decode_step(p, cfg_arch, t, pos, c))
+
+        # named functions: the device trace finds the programs as
+        # ``jit_prefill`` and ``jit_decode_step``
+        def prefill(p, b, c):
+            return model.prefill(p, cfg_arch, b, c)
+
+        def decode_step(p, t, pos, c):
+            return model.decode_step(p, cfg_arch, t, pos, c)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode_step)
 
     def _sample(self, logits, key, temperature):
         if temperature <= 0.0:
@@ -133,26 +141,23 @@ class Engine:
         block — with copy-on-write if that block is shared.  The returned
         bank cache keeps only non-paged state; its paged leaves stay zero.
         Returns ``(new_slots, tokens, heap)``."""
-        cache = view.assemble(heap, slots.cache)
-        pf = getattr(ctx, "prof", None)
-        if pf is not None and pf.enabled:
-            # the paged-attention kernel region proper: assembled K/V in,
-            # next-token logits out.  nbytes = assembled cache footprint
-            # (static .nbytes attrs — no device sync to compute the label)
-            import jax as _jax
-            kv_bytes = sum(leaf.nbytes
-                           for leaf in _jax.tree_util.tree_leaves(cache))
-            with pf.scope("paged_attn", nbytes=kv_bytes, path="engine",
-                          tier="local",
-                          work_items=int(slots.active.sum())) as ps:
-                logits, new_cache = self._decode(
-                    self.params, slots.tok[:, None], slots.pos, cache)
-                logits = ps(logits)
-        else:
-            logits, new_cache = self._decode(self.params, slots.tok[:, None],
-                                             slots.pos, cache)
+        with prof.scope(ctx, "kv_assemble"):
+            cache = view.assemble(heap, slots.cache)
+        # the paged-attention kernel region proper: assembled K/V in,
+        # next-token logits out.  nbytes = assembled cache footprint
+        # (static .nbytes attrs — no device sync to compute the label)
+        with prof.scope(ctx, "paged_attn", lambda: dict(
+                nbytes=sum(leaf.nbytes
+                           for leaf in jax.tree_util.tree_leaves(cache)),
+                path="engine", tier="local",
+                work_items=int(slots.active.sum()))) as ps:
+            logits, new_cache = self._decode(
+                self.params, slots.tok[:, None], slots.pos, cache)
+            logits = ps(logits)
         tok = self._sample(logits, key, temperature)
-        heap = view.writeback(ctx, heap, new_cache, slots.pos, slots.active)
+        with prof.scope(ctx, "kv_writeback"):
+            heap = view.writeback(ctx, heap, new_cache, slots.pos,
+                                  slots.active)
         mask = jnp.asarray(slots.active)
         return SlotBatch(
             cache=view.strip(new_cache),

@@ -66,6 +66,7 @@ class PagedDecodeView:
         self.num_slots = num_slots
         self.slots: Dict[int, _SlotMap] = {}
         self.cow_copies = 0
+        self.host_reads = 0      # blocking device-to-host reads (writeback)
 
     # ------------------------------------------------------------ lifecycle
     def attach(self, heap, slot: int, req_id: int, *,
@@ -162,7 +163,8 @@ class PagedDecodeView:
         if not lay.paged:
             return heap
         T, W = lay.block_tokens, lay.cache_width
-        pos = np.asarray(pos)
+        pos = np.asarray(pos)                  # blocking device-to-host read
+        self.host_reads += 1
         for s in range(self.num_slots):
             if not active[s] or s not in self.slots:
                 continue

@@ -69,6 +69,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from repro.obs import prof
 from repro.serve import kvpool as kvpool_mod
 from repro.serve.engine import Engine, ServeConfig
 from repro.serve.kvxfer import (EXTRA_SIGNALS, KVMigrator, StreamState,
@@ -151,6 +152,15 @@ class Request:
     def prompt_len(self) -> int:
         return int(self.batch["tokens"].shape[1])
 
+    @property
+    def decode_pos(self) -> int:
+        """The slot's next decode position, counted on the host: the prompt
+        plus every token emitted since admission but the pending one (a
+        replay holds ``out`` and counts ``replayed`` instead)."""
+        emitted = (self.replayed if self.replayed < self.replay_target
+                   else len(self.out))
+        return self.prompt_len + emitted - 1
+
 
 @dataclasses.dataclass
 class PrefixEntry:
@@ -230,13 +240,15 @@ class SchedStats:
     # collapses to the admission step, which is the A/B comparison)
     ttfd_first_block_steps: List[int] = dataclasses.field(
         default_factory=list)
-    # frontend-visible latencies: measured from *arrival*, so queue time
-    # before prefill counts (the satellite fix — percentiles over these)
-    queue_delay_steps: List[int] = dataclasses.field(default_factory=list)
-    ttfd_arrival_steps: List[int] = dataclasses.field(default_factory=list)
-    ttfd_arrival_model_s: List[float] = dataclasses.field(
-        default_factory=list)
+    # measured from *arrival*, so queue time before prefill counts
     e2e_steps: List[int] = dataclasses.field(default_factory=list)
+    # whole-pool rebuilds by this scheduler's steps (``core/heap.py``
+    # StoreTally deltas, so pods sharing one heap never count twice)
+    heap_stores: int = 0
+    heap_store_bytes: int = 0
+    # blocking device-to-host reads on the decode path: each slot's token,
+    # and the paged writeback's read of the slot positions
+    host_reads: int = 0
 
 
 class DisaggScheduler:
@@ -493,22 +505,13 @@ class DisaggScheduler:
         stream — all blocks issued, waiting slot-less for a decode slot.
         Parked streams keep draining under other requests' compute and bind
         a slot the moment one frees (tail + header only)."""
-        pf = self._prof()
         for req in list(self.streaming):
             st = req.stream
-            if pf is not None:
-                tier = self.ctx.tier(st.src_pe, st.dst_pe)
-                with pf.scope("stream_flush",
-                              nbytes=st.sent * self._block_bytes,
-                              path="proxy" if tier == "dcn" else "direct",
-                              tier=tier,
-                              work_items=self.migrator.work_items) as ps:
-                    self.heap = ps(self.migrator.stream_flush(self.heap, st))
-            else:
-                self.heap = self.migrator.stream_flush(self.heap, st)
+            self._stream_flush(st)
             if st.pending:
-                self.heap = self.migrator.stream_chunk(self.heap, st,
-                                                       self.stream_chunks)
+                with prof.scope(self.ctx, "kv_stage"):
+                    self.heap = self.migrator.stream_chunk(
+                        self.heap, st, self.stream_chunks)
             if not st.pending:
                 self.streaming.remove(req)
                 req.state = PARKED
@@ -517,18 +520,18 @@ class DisaggScheduler:
                                   end_args={"chunks": st.chunks,
                                             "blocks_sent": st.sent})
         for req in self.policy.waiting_order(list(self.parked)):
-            st = req.stream
-            if pf is not None:
-                tier = self.ctx.tier(st.src_pe, st.dst_pe)
-                with pf.scope("stream_flush",
-                              nbytes=st.sent * self._block_bytes,
-                              path="proxy" if tier == "dcn" else "direct",
-                              tier=tier,
-                              work_items=self.migrator.work_items) as ps:
-                    self.heap = ps(self.migrator.stream_flush(self.heap, st))
-            else:
-                self.heap = self.migrator.stream_flush(self.heap, st)
-            self._try_bind(req)
+            self._stream_flush(req.stream)
+            with prof.scope(self.ctx, "kv_stage"):
+                self._try_bind(req)
+
+    def _stream_flush(self, st) -> None:
+        def labels():
+            tier = self.ctx.tier(st.src_pe, st.dst_pe)
+            return dict(nbytes=st.sent * self._block_bytes,
+                        path="proxy" if tier == "dcn" else "direct",
+                        tier=tier, work_items=self.migrator.work_items)
+        with prof.scope(self.ctx, "stream_flush", labels) as ps:
+            self.heap = ps(self.migrator.stream_flush(self.heap, st))
 
     def _phase_prefill(self) -> None:
         """Advance streams, retry slot assignment for already-staged
@@ -538,7 +541,8 @@ class DisaggScheduler:
         baseline: the head)."""
         self._phase_stream()
         for _ in range(len(self.staged)):
-            self._try_migrate(self.staged.popleft())
+            with prof.scope(self.ctx, "kv_stage"):
+                self._try_migrate(self.staged.popleft())
         for _ in range(self.prefills_per_step):
             if not self.queue:
                 return
@@ -551,8 +555,6 @@ class DisaggScheduler:
                 del self.queue[idx]
                 req.prefill_pe = pe
                 req.prefill_step = self._step
-                self.stats.queue_delay_steps.append(
-                    self._step - req.arrival_step)
                 self._trace_phase(
                     req, "prefill",
                     end_args={"queue_steps": self._step - req.arrival_step},
@@ -562,17 +564,12 @@ class DisaggScheduler:
                     tr.begin("prefill", "sched", self._trace_pid, f"pe{pe}",
                              rid=req.rid, prompt_len=req.prompt_len)
                 key = jax.random.fold_in(self._key, req.rid)
-                pf = self._prof()
-                if pf is not None:
-                    with pf.scope("serve_prefill",
-                                  nbytes=req.prompt_len * self._token_bytes,
-                                  path="engine", tier="local") as ps:
-                        tok, _, cache1 = self.engine.prefill_request(
-                            req.batch, key, self.scfg.temperature)
-                        tok = ps(tok)
-                else:
+                with prof.scope(self.ctx, "serve_prefill", lambda: dict(
+                        nbytes=req.prompt_len * self._token_bytes,
+                        path="engine", tier="local")) as ps:
                     tok, _, cache1 = self.engine.prefill_request(
                         req.batch, key, self.scfg.temperature)
+                    tok = ps(tok)
                 req.first_token = tok
                 req.prefill_cache = cache1
                 self.stats.prefills += 1
@@ -580,7 +577,9 @@ class DisaggScheduler:
                     tr.end("prefill", "sched", self._trace_pid, f"pe{pe}")
             else:
                 del self.queue[idx]
-            if not self._stage(req):                 # pool exhausted: park
+            with prof.scope(self.ctx, "kv_stage"):
+                staged = self._stage(req)
+            if not staged:                           # pool exhausted: park
                 self.stats.stalled_on_pool += 1      # the prefilled request
                 self.queue.appendleft(req)
                 return
@@ -1039,10 +1038,6 @@ class DisaggScheduler:
             self.stats.admissions += 1
             self.stats.ttfd_steps.append(req.admit_step - req.submit_step)
             self.stats.ttfd_model_s.append(req.t_admit - req.t_submit)
-            self.stats.ttfd_arrival_steps.append(
-                req.admit_step - req.arrival_step)
-            self.stats.ttfd_arrival_model_s.append(
-                req.t_admit - req.t_arrival)
             self._maybe_finish(req)
         self.migrating = still
 
@@ -1085,43 +1080,41 @@ class DisaggScheduler:
                          slots=int(bank.active.sum()))
             # per-PE fold: decode PEs must not share sampling noise
             key = jax.random.fold_in(self._step_key, pe)
-            pf = self._prof()
-            if pf is not None:
-                # KV bytes the step reads: total context tokens across the
-                # PE's active slots (positions) at per-token KV size
-                ctx_tokens = int(bank.pos[bank.active].sum())
-                with pf.scope("serve_decode",
-                              nbytes=ctx_tokens * self._token_bytes,
-                              path="engine", tier="local",
-                              work_items=int(bank.active.sum())) as ps:
-                    if self.paged:
-                        bank, toks, self.heap = self.engine.decode_slots_paged(
-                            bank, key, self.ctx, self.heap, self.views[pe],
-                            self.scfg.temperature)
-                    else:
-                        bank, toks = self.engine.decode_slots(
-                            bank, key, self.scfg.temperature)
-                    toks = ps(toks)
-            elif self.paged:
-                bank, toks, self.heap = self.engine.decode_slots_paged(
-                    bank, key, self.ctx, self.heap, self.views[pe],
-                    self.scfg.temperature)
-            else:
-                bank, toks = self.engine.decode_slots(
-                    bank, key, self.scfg.temperature)
+            # KV bytes the step reads: total context tokens across the PE's
+            # active slots (positions, from the host's own count — reading
+            # ``bank.pos`` would sync the device) at per-token KV size
+            with prof.scope(self.ctx, "serve_decode", lambda: dict(
+                    nbytes=sum(self.requests[rid].decode_pos
+                               for rid in self.slot_req[pe]
+                               if rid is not None) * self._token_bytes,
+                    path="engine", tier="local",
+                    work_items=int(bank.active.sum()))) as ps:
+                if self.paged:
+                    view = self.views[pe]
+                    reads = view.host_reads
+                    bank, toks, self.heap = self.engine.decode_slots_paged(
+                        bank, key, self.ctx, self.heap, view,
+                        self.scfg.temperature)
+                    self.stats.host_reads += view.host_reads - reads
+                else:
+                    bank, toks = self.engine.decode_slots(
+                        bank, key, self.scfg.temperature)
+                toks = ps(toks)
             self.banks[pe] = bank
             stepped = True
             if tr is not None:
                 tr.end("decode", "sched", self._trace_pid, f"pe{pe}")
-            for s, rid in enumerate(self.slot_req[pe]):
-                if rid is None:
-                    continue
-                req = self.requests[rid]
-                if req.state != DECODING:
-                    continue
-                self._emit_token(req, int(toks[s]))
-                self.stats.decode_tokens += 1
-                self._maybe_finish(req)
+            with prof.scope(self.ctx, "token_read"):
+                for s, rid in enumerate(self.slot_req[pe]):
+                    if rid is None:
+                        continue
+                    req = self.requests[rid]
+                    if req.state != DECODING:
+                        continue
+                    self._emit_token(req, int(toks[s]))
+                    self.stats.host_reads += 1
+                    self.stats.decode_tokens += 1
+                    self._maybe_finish(req)
         if stepped:
             self.stats.decode_steps += 1
 
@@ -1144,7 +1137,8 @@ class DisaggScheduler:
                           "e2e_steps": req.finish_step - req.arrival_step,
                           "tokens": len(req.out),
                           "preemptions": req.preemptions})
-            self._evict(req)
+            with prof.scope(self.ctx, "sched_evict"):
+                self._evict(req)
 
     def _evict(self, req: Request) -> None:
         """Refcount-correct teardown: un-triggered COW reserves go first
@@ -1183,11 +1177,20 @@ class DisaggScheduler:
         pf = self._prof()
         if pf is not None:
             pf.set_step(self._step)
-        self._phase_recover()
-        self._phase_prefill()
-        self._phase_admit()
-        self._phase_resume()
-        self._phase_decode()
+        tally = self.heap.tally
+        stores, nbytes = tally.stores, tally.store_bytes
+        with prof.scope(self.ctx, "sched_recover"):
+            self._phase_recover()
+        with prof.scope(self.ctx, "sched_prefill"):
+            self._phase_prefill()
+        with prof.scope(self.ctx, "sched_admit"):
+            self._phase_admit()
+        with prof.scope(self.ctx, "sched_resume"):
+            self._phase_resume()
+        with prof.scope(self.ctx, "sched_decode"):
+            self._phase_decode()
+        self.stats.heap_stores += tally.stores - stores
+        self.stats.heap_store_bytes += tally.store_bytes - nbytes
         self._step += 1
 
     def done(self) -> bool:

@@ -287,9 +287,8 @@ def test_stream_signal_exhaustion_backpressures():
 
 
 def test_arrival_time_threads_into_latency_stats():
-    """A request submitted with an arrival_step in the past reports TTFD
-    from ARRIVAL (queue time included), while the migration-window stats
-    keep their old meaning; queue delay is recorded at prefill."""
+    """A request submitted with an arrival_step in the past reports its
+    end-to-end steps from ARRIVAL (queue time included)."""
     cfg, eng = _engine()
     ctx, heap = context.init(npes=4, node_size=4)
     pool = KVPool.create(heap, cfg, MAXLEN, num_blocks=48, max_slots=2,
@@ -299,11 +298,6 @@ def test_arrival_time_threads_into_latency_stats():
     sched.submit({"tokens": p}, arrival_step=-5, t_arrival=-1.0)
     sched.run()
     st = sched.stats
-    assert st.ttfd_arrival_steps[0] == st.ttfd_steps[0] + 5
-    assert st.queue_delay_steps[0] == 5
-    # the modeled arrival clock was handed in, so the arrival window is
-    # strictly wider than the migration window
-    assert st.ttfd_arrival_model_s[0] > st.ttfd_model_s[0]
     req = sched.requests[0]
     assert req.finish_step >= req.admit_step >= req.prefill_step
     assert st.e2e_steps[0] == req.finish_step + 5
