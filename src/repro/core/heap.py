@@ -13,6 +13,8 @@ is materialized, which makes every op testable against a numpy oracle.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -169,10 +171,14 @@ class SymmetricHeap:
 
     # ----------------------------------------------------------- access
     def read(self, ptr: SymPtr, pe) -> jnp.ndarray:
-        """Local load of the buffer as seen at PE ``pe``."""
-        flat = jax.lax.dynamic_slice(
-            self.pools[ptr.dtype][pe], (ptr.offset,), (max(ptr.size, 1),))
-        return flat[: ptr.size].reshape(ptr.shape)
+        """Local load of the buffer as seen at PE ``pe``: one slice of the
+        ``(npes, words)`` pool, ``ptr.size`` words long; no PE row is copied
+        out first.  A negative ``pe`` counts from the end; a Python int out
+        of ``[-npes, npes)`` raises IndexError; a traced one is clamped."""
+        pool = self.pools[ptr.dtype]
+        if isinstance(pe, int) and not -pool.shape[0] <= pe < pool.shape[0]:
+            raise IndexError(f"PE {pe} of {pool.shape[0]}")
+        return _heap_read(pool, pe, ptr.offset, tuple(ptr.shape))
 
     def write(self, ptr: SymPtr, pe, value) -> "SymmetricHeap":
         value = jnp.asarray(value, jnp.dtype(ptr.dtype)).reshape((ptr.size,))
@@ -200,6 +206,14 @@ class SymmetricHeap:
         return SymmetricHeap(self.npes, pools, dict(self._cursor),
                              {k: list(v) for k, v in self._free.items()},
                              self.words_per_pool, self.tally)
+
+
+@functools.partial(jax.jit, static_argnames=("shape",))
+def _heap_read(pool, pe, offset, shape):
+    size = math.prod(shape)
+    # dynamic_slice wraps a negative start and clamps one out of range
+    flat = jax.lax.dynamic_slice(pool, (pe, offset), (1, max(size, 1)))
+    return flat[0, :size].reshape(shape)
 
 
 def create(npes: int, words_per_pool: int = 1 << 20) -> SymmetricHeap:

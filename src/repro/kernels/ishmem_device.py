@@ -24,7 +24,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -149,12 +148,7 @@ def fused_paged_attn(wg, heap, view, q, *, unit_idx=None, layer: int = 0,
         view.pool.num_blocks, lay.block_words)
     data = jnp.concatenate(
         [data, jnp.zeros((1, lay.block_words), data.dtype)], axis=0)
-    nb = lay.blocks_per_request
-    table = np.full((view.num_slots, nb), view.pool.num_blocks, np.int32)
-    for s, sm in view.slots.items():
-        ids = view.pool.blocks_of(sm.req_id)
-        table[s, :len(ids)] = ids
-    pay = paged_gather(data, table)
+    pay = paged_gather(data, view.table())
     offs = _leaf_offsets(lay)
     k = _extract_leaf(pay, lay, k_leaf, view.num_slots,
                       offs[(unit_idx, "k")])[layer]

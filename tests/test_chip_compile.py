@@ -11,6 +11,8 @@ Only one process at a time may load the TPU library, so the topology is
 described inside a module fixture, never while a module is imported; keep
 every such rehearsal in this one file.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -21,15 +23,19 @@ from jax.sharding import NamedSharding, PartitionSpec as P, \
 
 from repro import kernels
 from repro.configs import base as cfgbase
+from repro.core import heap as heap_mod
 from repro.kernels import ishmem_device, ops
 from repro.launch.mesh import make_mesh
 from repro.models import model
 from repro.serve import kvpool
+from repro.serve.paged_attn import assemble_pages
 
 NPES = 4
 HBM_BYTES = 16e9            # one v5e chip
 SERVE_MAX_LEN = 144         # 128-token prompts + 16 new tokens
 SERVE_BLOCKS = 64
+BENCH_MAX_LEN = 1056        # the benchmark's qwen3-4b pool: 66-block tables
+BENCH_BLOCKS = 140          # over 140 blocks on each of 3 PEs
 
 
 @pytest.fixture(scope="module")
@@ -75,6 +81,54 @@ def test_paged_gather_full_block_width(one_chip, mosaic):
     compiled = jax.jit(ishmem_device.paged_gather).lower(
         data, table).compile()
     _assert_kernel(compiled)
+
+
+def _element_counts(hlo):
+    """Element count of every array shape written in an HLO text."""
+    return {int(np.prod([int(d) for d in dims.split(",") if d]))
+            for dims in re.findall(r"\b(?:bf16|f32|s32|pred)\[([\d,]*)\]",
+                                   hlo)}
+
+
+def _bench_pool(one_chip):
+    cfg = cfgbase.get_config("qwen3_4b")
+    lay = kvpool.build_layout(cfg, BENCH_MAX_LEN, block_tokens=16)
+    pool = _sds((3, BENCH_BLOCKS * lay.block_words), lay.kv_dtype, one_chip)
+    return lay, pool
+
+
+def test_assemble_pages_reads_only_the_mapped_blocks(one_chip):
+    """Paged-decode assembly at qwen3-4b width over the benchmark's pool:
+    no array the size of a PE's pool row is made, and the program's
+    temporary buffers stay under twice the 156 MB it gathers."""
+    lay, pool = _bench_pool(one_chip)
+    nb = lay.blocks_per_request
+    table = _sds((1, nb), jnp.int32, one_chip)
+    pe = _sds((), jnp.int32, one_chip)
+    compiled = assemble_pages.lower(
+        pool, pe, table, layout=lay, data_off=0,
+        num_blocks=BENCH_BLOCKS).compile()
+    counts = _element_counts(compiled.as_text())
+    assert 3 * BENCH_BLOCKS * lay.block_words in counts     # the pool
+    assert BENCH_BLOCKS * lay.block_words not in counts
+    gathered = nb * lay.block_words * jnp.dtype(lay.kv_dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * gathered
+
+
+def test_heap_read_of_a_block_slices_the_pool(one_chip):
+    """``SymmetricHeap.read`` of one KV block from the benchmark's pool is
+    one program that slices the 2-D pool and makes no row of it."""
+    lay, pool = _bench_pool(one_chip)
+    ptr = heap_mod.SymPtr(lay.kv_dtype, 7 * lay.block_words,
+                          (lay.block_words,))
+    pe = _sds((), jnp.int32, one_chip)
+    read = jax.jit(lambda p, q: heap_mod.SymmetricHeap(
+        3, {lay.kv_dtype: p}).read(ptr, q))
+    compiled = read.lower(pool, pe).compile()
+    counts = _element_counts(compiled.as_text())
+    assert 3 * BENCH_BLOCKS * lay.block_words in counts     # the pool
+    assert BENCH_BLOCKS * lay.block_words not in counts
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 def _ring_fns():

@@ -1,3 +1,4 @@
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -134,3 +135,41 @@ def test_allocations_never_overlap(allocs):
         for (l2, h2) in spans[dt]:
             assert hi <= l2 or lo >= h2, "overlapping allocation"
         spans[dt].append((lo, hi))
+
+
+def _filled(npes=3):
+    """A heap whose pools of every dtype hold distinct values at every PE."""
+    h = heap_mod.create(npes, 1024)
+    ptrs = {}
+    for dt in ("float32", "int32", "bfloat16"):
+        ptrs[dt] = {"vec": h.malloc((5,), dt), "mat": h.malloc((2, 3), dt),
+                    "scalar": h.malloc((), dt), "empty": h.malloc((0,), dt)}
+        words = h.pools[dt].shape[1]
+        h = h.write_all(heap_mod.SymPtr(dt, 0, (words,)),
+                        np.arange(npes * words).reshape(npes, words) % 251)
+    return h, ptrs
+
+
+@pytest.mark.parametrize("dt", ["float32", "int32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["vec", "mat", "scalar", "empty"])
+@pytest.mark.parametrize("pe", [0, 2, -1, -3])
+@pytest.mark.parametrize("traced", [False, True])
+def test_read_slices_the_pool_at_the_pe(dt, kind, pe, traced):
+    """``read`` is the numpy slice of the PE's words, for Python-int,
+    negative and traced PEs, and for scalar and zero-size pointers."""
+    h, ptrs = _filled()
+    ptr = ptrs[dt][kind]
+    want = np.asarray(h.pools[dt])[pe, ptr.offset:ptr.offset + ptr.size]
+    if traced:
+        got = jax.jit(lambda q: h.read(ptr, q))(np.int32(pe))
+    else:
+        got = h.read(ptr, pe)
+    assert got.shape == ptr.shape and got.dtype == h.pools[dt].dtype
+    np.testing.assert_array_equal(np.asarray(got).reshape(-1), want)
+
+
+def test_read_rejects_a_pe_out_of_range():
+    h, ptrs = _filled()
+    for pe in (3, -4, 7):
+        with pytest.raises(IndexError):
+            h.read(ptrs["float32"]["vec"], pe)

@@ -1,6 +1,6 @@
 """Paged decode + chunked streaming + shared-prefix policy (DESIGN.md §9).
 
-Three guarantee families:
+Four guarantee families:
 
 1. **streamed admission never reads ahead of its signal** — property-tested
    against the pending-queue oracle, chunk by chunk: blocks whose
@@ -16,7 +16,12 @@ Three guarantee families:
 3. **the decode path really is paged** — assembled leaves come from the
    pool row (the slot banks never re-grow a dense K/V copy), and outputs
    stay bitwise-identical to the lockstep baseline throughout.
+4. **assembly is one program over the mapped blocks** — byte-equal to the
+   eager row-gather formula it replaced, compiled once for every table
+   and decode PE.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,10 +29,11 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import base as cfgbase
-from repro.core import context
-from repro.models import model
+from repro.core import context, heap as heap_mod
+from repro.models import kvcache, model
 from repro.serve.engine import Engine, ServeConfig
 from repro.serve.kvpool import KVPool
+from repro.serve.paged_attn import PagedDecodeView, _SlotMap, assemble_pages
 from repro.serve.kvxfer import EXTRA_SIGNALS, KVMigrator
 from repro.serve.scheduler import DisaggScheduler
 
@@ -442,3 +448,128 @@ def test_growth_blocks_receive_decode_writes():
     base = eng.generate({"tokens": p}, ServeConfig(max_new_tokens=NEW))
     np.testing.assert_array_equal(np.asarray(base[0]),
                                   np.asarray(sched.requests[0].out))
+
+
+# ---------------------------------------------------------------------------
+# 4. assembly reads the pool in place, in one program
+# ---------------------------------------------------------------------------
+
+
+def _eager_assemble(view, heap, cache):
+    """The eager assembly formula the jitted program replaced, in numpy:
+    the PE's whole pool row as ``(num_blocks, block_words)``, a trailing
+    zero page for unmapped entries, a row gather through the table, then
+    each leaf cut, transposed and reshaped."""
+    pool, lay = view.pool, view.pool.layout
+    row = np.asarray(heap.pools[lay.kv_dtype])[view.pe]
+    data = row[pool.data.offset:pool.data.offset
+               + pool.num_blocks * lay.block_words].reshape(
+                   pool.num_blocks, lay.block_words)
+    data = np.concatenate([data, np.zeros((1, lay.block_words), data.dtype)])
+    nb, T = lay.blocks_per_request, lay.block_tokens
+    table = np.full((view.num_slots, nb), pool.num_blocks, np.int32)
+    for s, sm in view.slots.items():
+        ids = pool.blocks_of(sm.req_id)
+        table[s, :len(ids)] = ids
+    pay = data[table]
+    blocks = [dict(e) for e in cache["blocks"]]
+    off = 0
+    for pl in lay.paged:
+        n = pl.words_per_token * T
+        leaf = pay[:, :, off:off + n].reshape(
+            view.num_slots, nb, pl.reps, T, pl.nkv, pl.hd)
+        off += n
+        leaf = leaf.transpose(2, 0, 1, 3, 4, 5).reshape(
+            pl.reps, view.num_slots, nb * T, pl.nkv, pl.hd)[:, :, :pl.width]
+        ref = blocks[pl.unit_idx][pl.key]
+        blocks[pl.unit_idx][pl.key] = leaf.astype(ref.dtype)
+    return blocks
+
+
+def _assert_same_bytes(view, heap, cache):
+    got = view.assemble(heap, cache)["blocks"]
+    want = _eager_assemble(view, heap, cache)
+    for pl in view.pool.layout.paged:
+        g = np.asarray(got[pl.unit_idx][pl.key])
+        w = want[pl.unit_idx][pl.key]
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(g.view(np.uint8), w.view(np.uint8))
+
+
+def _filled_view(attention, block_tokens, num_slots, pe=2, num_blocks=23):
+    """A decode view over a pool whose every word is random, with slots
+    armed on tables of several lengths (slot 1 of three left unarmed)."""
+    cfg = cfgbase.reduced(cfgbase.get_config("qwen3_4b"))
+    if attention == "swa":
+        cfg = dataclasses.replace(cfg, attention="swa", window=10)
+    ctx, heap = context.init(npes=4, node_size=4)
+    pool = KVPool.create(heap, cfg, MAXLEN, num_blocks=num_blocks,
+                         max_slots=num_slots, block_tokens=block_tokens)
+    assert pool.layout.ring == (attention == "swa")
+    dt = pool.layout.kv_dtype
+    heap = heap.write_all(heap_mod.SymPtr(dt, 0, heap.pools[dt].shape[1:]),
+                          jax.random.normal(jax.random.key(3),
+                                            heap.pools[dt].shape))
+    view = PagedDecodeView(pool, pe, num_slots)
+    nb = pool.layout.blocks_per_request
+    for s in range(num_slots):
+        if num_slots == 3 and s == 1:
+            continue                              # an unarmed slot: all zeros
+        n = max(1, nb - 2 * s)                    # short tables leave zeros
+        pool.alloc(100 + s, n)
+        view.slots[s] = _SlotMap(req_id=100 + s, cow={})
+    cache = kvcache.init_cache(cfg, num_slots, MAXLEN)
+    return view, heap, cache
+
+
+@pytest.mark.parametrize("num_slots", [1, 3])
+@pytest.mark.parametrize("attention,block_tokens", [
+    ("full", 4), ("full", 5), ("swa", 4)])
+def test_assemble_is_byte_equal_to_the_eager_formula(attention, block_tokens,
+                                                     num_slots):
+    """Dense and ring layouts, a ragged last block (5 does not divide the
+    24-token cache), unmapped table entries and an unarmed slot: the one
+    program gives the eager formula's bytes."""
+    view, heap, cache = _filled_view(attention, block_tokens, num_slots)
+    _assert_same_bytes(view, heap, cache)
+
+
+def test_assemble_is_byte_equal_around_copy_on_write():
+    """A shared-prefix run with diverging sampled decodes: at every step,
+    before and after the copy-on-write remaps a table entry, the assembled
+    cache of every decode PE equals the eager formula's."""
+    cfg, params, ctx, heap, eng, pool = _setup()
+    sched = _sched(ctx, heap, eng, pool, decode_pes=[2], num_slots=2, NEW=6,
+                   temperature=0.7, shared_prefix=True)
+    p = _prompt(cfg, S=10)
+    for _ in range(2):
+        sched.submit({"tokens": p}, prefix_len=10)
+    shared = cowed = 0
+    guard = 0
+    while not sched.done():
+        sched.step()
+        guard += 1
+        assert guard < 200
+        view = sched.views[2]
+        tables = [pool.blocks_of(sm.req_id) for sm in view.slots.values()]
+        if len(tables) == 2 and set(tables[0]) & set(tables[1]):
+            shared += 1
+        cowed += view.cow_copies > 0 and len(tables) > 0
+        _assert_same_bytes(view, sched.heap, sched.banks[2].cache)
+    assert shared and cowed and sched.stats.cow_copies >= 1
+
+
+def test_assemble_pages_compiles_once_across_tables_and_pes():
+    """Changing tables and both decode PEs reuse one program: the PE and
+    the table are traced, only the layout and the pool's place are
+    static."""
+    view, heap, cache = _filled_view("full", 4, 3, num_blocks=29)
+    before = assemble_pages._cache_size()
+    for step in range(4):
+        for pe in (2, 3):
+            view.pe = pe
+            _assert_same_bytes(view, heap, cache)
+        view.pool.release(view.slots[0].req_id)      # slot 0 re-admits
+        view.pool.alloc(200 + step, 1 + step)
+        view.slots[0] = _SlotMap(req_id=200 + step, cow={})
+    assert assemble_pages._cache_size() - before == 1
