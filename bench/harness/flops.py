@@ -1,4 +1,5 @@
-"""Operations and bytes the work needs, computed from shapes.
+"""Operations and bytes the work of a dense decoder needs, computed from
+shapes: the counts of the ``dense`` model family (``families/dense.py``).
 
 These are the algorithm's counts, not the program's: a later change that
 does less work than this (or more) is measured against the same yardstick.

@@ -1,4 +1,5 @@
-"""Plain float32 reference of a dense GQA decoder, and its fp8 control.
+"""Plain float32 reference of a dense GQA decoder, and its fp8 control: the
+reference of the ``dense`` model family (``families/dense.py``).
 
 It follows the published description of a Llama/Qwen3-style block and
 imports nothing of the program: pre-norm RMSNorm, optional per-head RMSNorm

@@ -3,10 +3,12 @@
 ``BENCHMARK.json`` lists cells and metrics.  A cell's own file
 (``workloads/<cell>.json``) names its configuration, its traffic mix and its
 driver kind; each of those is a file of its own (``configs/<name>.json``,
-``traffic/<name>.json``, ``drivers/<kind>.py``), and each per-layer metric
-is a reader ``metrics/<name>.py``.  Adding a cell, a configuration, a
-traffic mix, a driver kind or a metric is adding files: nothing here lists
-them.
+``traffic/<name>.json``, ``drivers/<kind>.py``).  A configuration names its
+model family (``"family"``), a module ``families/<family>.py`` that holds
+what depends on the architecture (``FAMILY``), and each per-layer metric is
+a reader ``metrics/<name>.py``.  Adding a cell, a configuration, a traffic
+mix, a model family, a driver kind or a metric is adding files: nothing here
+lists them.
 """
 from __future__ import annotations
 
@@ -18,6 +20,14 @@ import re
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+
+#: what a family module exposes: the program's ``ArchConfig`` at the
+#: configuration's widths, the plain reference
+#: (``Reference(cfg, seed, *, rows)`` with ``.gaps`` and ``.logits``), the
+#: work counted from shapes, and the program's span names the family adds
+#: to the traced run's host activities
+FAMILY = ("arch_config", "Reference", "prefill_flops", "token_flops",
+          "kv_bytes_per_token", "decode_bytes", "SPANS")
 
 
 class Registry:
@@ -68,6 +78,15 @@ class Registry:
 
     def reader(self, metric: str):
         return self._module("metrics", metric)
+
+    def family(self, name: str):
+        """The model family module ``families/<name>.py``, which has to
+        expose every name of ``FAMILY``."""
+        mod = self._module("families", name)
+        missing = [k for k in FAMILY if not hasattr(mod, k)]
+        if missing:
+            raise AttributeError(f"{mod.__file__} lacks {', '.join(missing)}")
+        return mod
 
     # ----------------------------------------------------------- metrics
     def end_to_end(self, cell: str) -> list:

@@ -13,8 +13,8 @@ the wait a slow step imposes on later requests counts.
 
 After the window: the peak device memory is read, a seeded sample of the
 finished requests (the longest among them) is kept on the host, the
-program's state is dropped, and the plain reference scores each served
-token by how far its logit lies below the reference's best.
+program's state is dropped, and the plain reference of the configuration's
+model family scores each served token by how far its logit lies below the reference's best.
 """
 from __future__ import annotations
 
@@ -24,40 +24,15 @@ import time
 
 import numpy as np
 
-from . import flops, traffic as traffic_mod, weights
+from . import traffic as traffic_mod, weights
 from .trace import annotate
-from .reference import DenseReference
 from .session import Outcome, log, peak_memory
 
 #: host spans the traced run records (outermost first): the harness's own,
-#: then the program's wall-clock profiler scopes
+#: then the program's wall-clock profiler scopes; the configuration's
+#: family appends its own (``SPANS``)
 ACTIVITIES = ("fleet_step", "arrivals_wait", "stamp", "serve_prefill",
               "stream_flush", "serve_decode", "paged_attn")
-
-
-def arch_config(cfg: dict):
-    """The program's ``ArchConfig`` for a dense decoder configuration file
-    (Hugging Face key names)."""
-    from repro.configs.base import ArchConfig
-    if cfg.get("hidden_act", "silu") != "silu":
-        raise ValueError("only SwiGLU (silu) MLPs are served")
-    if float(cfg["rms_norm_eps"]) != 1e-6:
-        raise ValueError("the program's RMSNorm uses eps 1e-6; the "
-                         f"configuration states {cfg['rms_norm_eps']}")
-    window = cfg.get("sliding_window")
-    return ArchConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg.get("head_dim") or 0,
-        attention="swa" if window else "full", window=window or 4096,
-        qk_norm=bool(cfg.get("qk_norm", False)),
-        rope_theta=float(cfg["rope_theta"]), mlp_type="swiglu",
-        tie_embeddings=bool(cfg.get("tie_word_embeddings", False)),
-        dtype=cfg["torch_dtype"], param_dtype=cfg["torch_dtype"],
-        remat=False, source=cfg.get("source", ""))
 
 
 class _Annotated:
@@ -96,6 +71,7 @@ class Serving:
     def __init__(self, sess):
         self.sess = sess
         self.cfg = sess.config
+        self.family = sess.family
         self.fl = sess.workload["fleet"]
 
     # -------------------------------------------------------------- set-up
@@ -105,7 +81,7 @@ class Serving:
         from repro.serve.engine import Engine
         from repro.serve.frontend import Fleet, FleetConfig
         sess, watch = self.sess, self.sess.watch
-        self.arch = arch_config(self.cfg)
+        self.arch = sess.arch
         t0, m0 = time.monotonic(), watch.mark()
         shapes = jax.eval_shape(
             lambda: model.init_params(jax.random.key(0), self.arch))
@@ -306,8 +282,10 @@ class Serving:
         return sum(1 for r in w["reqs"].values() if r.state == "shed")
 
     def records(self, arrivals, w) -> dict:
-        """What the per-layer readers read (traced run)."""
-        cfg, step0 = self.cfg, w["step0"]
+        """What the per-layer readers read (traced run).  Work is counted
+        by the configuration's family, which the records carry for the
+        readers' own counts."""
+        cfg, fam, step0 = self.cfg, self.family, w["step0"]
         s0, s1 = w["stats0"], w["stats1"]
         by_idx = {a.idx: a for a in arrivals}
         waits, fl = [], 0.0
@@ -315,14 +293,14 @@ class Serving:
             a = by_idx[idx]
             if req.prefill_step >= step0 and req.prefill_step in w["step_t"]:
                 waits.append(w["step_t"][req.prefill_step] - a.due_s)
-                fl += flops.prefill_flops(cfg, a.tokens.shape[1])
+                fl += fam.prefill_flops(cfg, a.tokens.shape[1])
             # each token but the first (which the prefill made) is a decode
             # step at context prompt + k - 1
             S = a.tokens.shape[1]
             for k in range(1, len(w["stamps"][idx])):
-                fl += flops.token_flops(cfg, S + k - 1)
+                fl += fam.token_flops(cfg, S + k - 1)
         delta = {k: s1.get(k, 0) - s0.get(k, 0) for k in s1}
-        return dict(kind="serve", cfg=cfg, fleet=self.fl,
+        return dict(kind="serve", cfg=cfg, family=fam, fleet=self.fl,
                     window_s=w["t_close"], sched=delta, queue_wait_s=waits,
                     model_flops=fl, prof=w["prof"], peaks=self.sess.peaks)
 
@@ -357,15 +335,16 @@ class Serving:
 
     def check(self, sample, *, control: bool = False) -> dict:
         """The widest gap of a served token's logit below the reference's
-        best, over the sample (infinite when nothing finished).  With
-        ``control``, also the widest gap of the fp8 control's top tokens
-        at the same positions (``control_max_logit_gap``)."""
+        best, over the sample (infinite when nothing finished), by the
+        reference of the configuration's family.  With ``control``, also
+        the widest gap of the fp8 control's top tokens at the same
+        positions (``control_max_logit_gap``)."""
         chk = self.sess.workload["check"]
         limit = chk["max_logit_gap"]
         if not sample:
             return {"max_logit_gap": (float("inf"), limit)}
-        ref = DenseReference(self.cfg, self.sess.seed,
-                             rows=chk.get("rows", 4))
+        ref = self.family.Reference(self.cfg, self.sess.seed,
+                                    rows=chk.get("rows", 4))
         t0 = time.monotonic()
         seqs = [np.concatenate([p, s[:-1]]) for _, p, s in sample]
         served = [s for _, _, s in sample]
@@ -416,8 +395,9 @@ def serve(sess, arrivals, *, control: bool = False) -> Outcome:
     trace_doc = None
     if sess.trace:
         t0 = time.monotonic()
-        tr = cap.trace(ACTIVITIES)
-        trace_doc = trace_mod.reduce(tr, ACTIVITIES)
+        activities = ACTIVITIES + sess.family.SPANS
+        tr = cap.trace(activities)
+        trace_doc = trace_mod.reduce(tr, activities)
         records["trace"] = tr
         log(f"trace: busy {trace_doc['busy_s']:.6f} s of "
             f"{trace_doc['window_s']:.6f} s, read in "

@@ -73,6 +73,7 @@ class Session:
     name: str
     workload: dict
     config: dict
+    family: object                         # families/<config's family>.py
     traffic: dict
     seed: int
     seconds: float
@@ -86,6 +87,12 @@ class Session:
     def window_opens(self) -> None:
         """Called by the driver at its first timed step: set-up ends."""
         self.t_window = time.monotonic()
+
+    @property
+    def arch(self):
+        """The program's ``ArchConfig`` at the configuration's widths, as
+        its family builds it."""
+        return self.family.arch_config(self.config)
 
     @property
     def setup_s(self) -> float:
@@ -128,6 +135,7 @@ def open_session(args, root: str, *, chips: Callable = find_chips,
                  bench: str = registry_mod.BENCH) -> Session:
     reg = registry_mod.Registry(root, bench)
     wl = reg.workload(args.workload)
+    cfg = reg.config(wl["config"])
     src = os.path.join(root, "src")
     if not os.path.isdir(os.path.join(src, "repro")):
         raise NoChip(f"no program source at {src}")
@@ -135,8 +143,8 @@ def open_session(args, root: str, *, chips: Callable = find_chips,
         sys.path.insert(0, src)
     devices = chips(int(wl["chips"]))
     from .compile_watch import CompileWatch
-    return Session(name=args.workload, workload=wl,
-                   config=reg.config(wl["config"]),
+    return Session(name=args.workload, workload=wl, config=cfg,
+                   family=reg.family(cfg["family"]),
                    traffic=reg.traffic(wl["traffic"]), seed=args.seed,
                    seconds=args.seconds, trace=bool(args.trace),
                    devices=devices, watch=CompileWatch(), registry=reg)

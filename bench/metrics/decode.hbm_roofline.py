@@ -1,8 +1,8 @@
 """The decode program's share of its HBM roofline.  Least time: the weights
 plus the KV of the active contexts (``serve_decode``'s bytes) at the HBM
-peak, summed over decode calls.  Device time: in each ``paged_attn`` span
-(the model call), the longest device program that overlaps it."""
-from bench.harness import flops
+peak, summed over decode calls, in the bytes the records' model family
+counts.  Device time: in each ``paged_attn`` span (the model call), the
+longest device program that overlaps it."""
 
 
 def read(rec):
@@ -26,8 +26,8 @@ def read(rec):
     if not t_dev:
         return None
     t_dev /= 1e9 * len(tr.devices())
-    cfg = rec["cfg"]
-    per_token = flops.kv_bytes_per_token(cfg)
-    least = sum(flops.decode_bytes(cfg, s.nbytes // per_token)
+    cfg, fam = rec["cfg"], rec["family"]
+    per_token = fam.kv_bytes_per_token(cfg)
+    least = sum(fam.decode_bytes(cfg, s.nbytes // per_token)
                 for s in dec) / rec["peaks"]["hbm_bytes_per_s"]
     return 100.0 * least / t_dev

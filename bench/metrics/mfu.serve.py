@@ -1,6 +1,7 @@
 """Model FLOPs of every prompt token prefilled and every output token
-decoded in the window (``harness/flops.py``, from shapes) over the window
-times the chip's bf16 peak."""
+decoded in the window (counted from shapes by the configuration's model
+family, ``families/<family>.py``) over the window times the chip's bf16
+peak."""
 
 
 def read(rec):

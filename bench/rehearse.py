@@ -4,7 +4,8 @@ TPU v5e, without a chip, and print their memory analysis.
 
     JAX_PLATFORMS=cpu python3 bench/rehearse.py --workload <cell>
 
-It builds the cell's configuration at its stated widths and compiles, for
+It builds the cell's configuration at its stated widths, as the
+configuration's model family makes its ``ArchConfig``, and compiles, for
 one chip of a described ``v5e:2x2``, the program's jitted prefill at every
 prompt length of the cell's traffic and its decode step over one decode
 PE's slot bank at the cell's ``max_len``, from shapes only.  What the chip's
@@ -31,14 +32,14 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench.harness import registry, serving
+    from bench.harness import registry
     from repro.models import kvcache, model
 
     jax.config.update("jax_enable_compilation_cache", False)
     reg = registry.Registry(ROOT)
     wl = reg.workload(args.workload)
     cfg = reg.config(wl["config"])
-    arch = serving.arch_config(cfg)
+    arch = reg.family(cfg["family"]).arch_config(cfg)
     fl = wl["fleet"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")

@@ -1,13 +1,17 @@
 """The plain float32 reference against the program's model at reduced
 size on the CPU, on the benchmark's seeded weights: qk-norm with a tied
-head (Qwen3-like) and head_dim 120 with a separate head (Danube-like)."""
+head (Qwen3-like) and head_dim 120 with a separate head (Danube-like), the
+program built as the ``dense`` family builds it."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench.harness import serving, weights
+from bench.harness import registry, weights
 from bench.harness.reference import DenseReference
+from bench.tests.tiny import REPO
+
+DENSE = registry.Registry(REPO).family("dense")
 
 QWEN_LIKE = {"name": "qwen-like", "hidden_size": 64, "intermediate_size": 96,
              "num_hidden_layers": 2, "num_attention_heads": 4,
@@ -26,7 +30,7 @@ DANUBE_LIKE = {"name": "danube-like", "hidden_size": 240,
                          ids=lambda c: c["name"])
 def test_reference_matches_program(cfg):
     from repro.models import kvcache, model
-    arch = serving.arch_config(cfg)
+    arch = DENSE.arch_config(cfg)
     seed = 2**33 + 17
     shapes = jax.eval_shape(lambda: model.init_params(jax.random.key(0),
                                                       arch))
@@ -58,7 +62,7 @@ def test_weights_are_the_programs_tree():
     """The benchmark fills exactly the program's parameter tree, and a leaf
     made again for one layer equals that layer of the stacked leaf."""
     from repro.models import model
-    arch = serving.arch_config(QWEN_LIKE)
+    arch = DENSE.arch_config(QWEN_LIKE)
     shapes = jax.eval_shape(lambda: model.init_params(jax.random.key(0),
                                                       arch))
     params = weights.make_params(shapes, 5)

@@ -1,5 +1,7 @@
-"""Every cell, configuration, traffic mix, driver and metric resolves by its
-name, and ``BENCHMARK.json`` keeps to the shape the benchmark promises."""
+"""Every cell, configuration, model family, traffic mix, driver and metric
+resolves by its name, and ``BENCHMARK.json`` keeps to the shape the
+benchmark promises."""
+import glob
 import json
 import os
 import re
@@ -75,7 +77,8 @@ def test_config_file(entry):
     assert len(entry["reduced"]) <= 16
     for k in entry["reduced"]:
         assert NAME.match(k) and not WIDTHS.search(k), k
-    assert {"source", "assumed", "deployment"} <= set(doc)
+    assert {"source", "assumed", "deployment", "family"} <= set(doc)
+    assert REG.family(doc["family"]).arch_config(doc).family == doc["family"]
     assert LINE.match(entry["source"]) and LINE.match(entry["why"])
     assert any(c["config"] == entry["name"] for c in SPEC["workloads"])
 
@@ -121,3 +124,37 @@ def test_unknown_names_are_refused():
         REG.config("../configs/qwen3-4b")
     with pytest.raises(KeyError):
         REG.workload("no-such-cell")
+
+
+@pytest.mark.parametrize("path", sorted(glob.glob(
+    os.path.join(REPO, "bench", "families", "*.py"))), ids=os.path.basename)
+def test_family_exposes_the_contract(path):
+    mod = REG.family(os.path.basename(path)[:-3])
+    for name in registry.FAMILY:
+        if name == "SPANS":
+            assert isinstance(mod.SPANS, tuple)
+            assert all(isinstance(s, str) and s for s in mod.SPANS)
+        else:
+            assert callable(getattr(mod, name)), name
+
+
+@pytest.mark.parametrize("name,error,says", [
+    ("no-such-family", FileNotFoundError,
+     os.path.join("families", "no-such-family.py")),
+    ("../drivers/serve_open_loop", ValueError, "../drivers/serve_open_loop"),
+    ("dense family", ValueError, "dense family"),
+])
+def test_unknown_family_is_refused(name, error, says):
+    with pytest.raises(error, match=re.escape(says)):
+        REG.family(name)
+
+
+def test_family_without_the_contract_is_refused(tmp_path):
+    """A family file that lacks a name of the contract is refused, and the
+    error names the file and what it lacks."""
+    from bench.tests import tiny
+    root, bench = tiny.make_tree(tmp_path, families={
+        "half": "SPANS = ()\n\ndef arch_config(cfg):\n    return None\n"})
+    with pytest.raises(AttributeError) as e:
+        registry.Registry(root, bench).family("half")
+    assert "half.py" in str(e.value) and "Reference" in str(e.value)

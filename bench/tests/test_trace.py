@@ -69,7 +69,8 @@ def test_decode_roofline_by_hand():
            "num_key_value_heads": 2, "head_dim": 16,
            "intermediate_size": 128, "num_hidden_layers": 1,
            "vocab_size": 256}
-    from bench.harness import flops
+    from bench.harness import flops, registry
+    from bench.tests.tiny import REPO
     kv = (int(4.095e9) - flops.weight_bytes(cfg)) // flops.kv_bytes_per_token(
         cfg) * flops.kv_bytes_per_token(cfg)
     tr = make([], [("bench_window", 0, 100 * ms),
@@ -78,6 +79,7 @@ def test_decode_roofline_by_hand():
               mods=[("assemble", 9 * ms, 3 * ms), ("decode", 12 * ms, 10 * ms),
                     ("decode", 51 * ms, 10 * ms)])
     rec = {"trace": tr, "cfg": cfg, "peaks": {"hbm_bytes_per_s": 819e9},
+           "family": registry.Registry(REPO).family("dense"),
            "prof": [S("serve_decode", kv), S("serve_decode", kv)]}
     assert reader("decode.hbm_roofline")(rec) == pytest.approx(50.0, rel=1e-6)
 

@@ -1,10 +1,11 @@
 """A throwaway benchmark tree at CPU size, built from files only.
 
 ``make_tree`` writes a checkout-like directory: ``BENCHMARK.json``, ``src``
-(a link to the program), and ``bench/`` holding links to the real drivers
-and metric readers plus tiny configuration, traffic and cell files.  That a
-cell runs from it shows a cell, a configuration and a traffic mix are added
-by adding files.
+(a link to the program), and ``bench/`` holding links to the real drivers,
+metric readers and model families plus tiny configuration, traffic and cell
+files, and any family module given as source text.  That a cell runs from
+it shows a cell, a configuration, a traffic mix and a model family are
+added by adding files.
 """
 from __future__ import annotations
 
@@ -16,6 +17,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 
 TINY = {
     "name": "tiny", "source": "a CPU-size dense decoder for tests",
+    "family": "dense",
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "vocab_size": 256, "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
@@ -48,8 +50,10 @@ def _write(path, doc):
         json.dump(doc, f, indent=1)
 
 
-def make_tree(tmp, *, configs=None, traffic=None, cells=None):
-    """Write the tree under ``tmp``; returns (root, bench dir)."""
+def make_tree(tmp, *, configs=None, traffic=None, cells=None,
+              families=None):
+    """Write the tree under ``tmp``; returns (root, bench dir).
+    ``families`` maps a family name to its module's source."""
     configs = dict({"tiny": TINY}, **(configs or {}))
     traffic = dict(TRAFFIC, **(traffic or {}))
     cells = dict(CELLS, **(cells or {}))
@@ -58,7 +62,7 @@ def make_tree(tmp, *, configs=None, traffic=None, cells=None):
     os.makedirs(bench, exist_ok=True)
     if not os.path.exists(os.path.join(root, "src")):
         os.symlink(os.path.join(REPO, "src"), os.path.join(root, "src"))
-    for sub in ("drivers", "metrics"):
+    for sub in ("drivers", "metrics", "families"):
         d = os.path.join(bench, sub)
         os.makedirs(d, exist_ok=True)
         for f in os.listdir(os.path.join(REPO, "bench", sub)):
@@ -71,6 +75,9 @@ def make_tree(tmp, *, configs=None, traffic=None, cells=None):
         _write(os.path.join(bench, "traffic", f"{name}.json"), doc)
     for name, doc in cells.items():
         _write(os.path.join(bench, "workloads", f"{name}.json"), doc)
+    for name, text in (families or {}).items():
+        with open(os.path.join(bench, "families", f"{name}.py"), "w") as f:
+            f.write(text)
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         spec = json.load(f)
     spec["configs"] = [{"name": n, "source": "test",
